@@ -95,8 +95,8 @@ def _drive_and_verify(eng, ring, disp, frames, bases, depth=RING_DEPTH):
         for f in win:
             assert ring.push(f)          # untagged: the default chain owns it
         disp.service()
-        o1 = np.asarray(_parse_frame_rows(win, True))
-        o2 = np.asarray(_dequant_trailing_rows(o1, True))
+        o1 = np.asarray(_parse_frame_rows(win))
+        o2 = np.asarray(_dequant_trailing_rows(o1))
         g1 = eng.read_buffer(DATA_PEER, s1, depth * PARSED_ROW
                              ).reshape(depth, PARSED_ROW)
         g2 = eng.read_buffer(DATA_PEER, s2, depth * HDR_BYTES
@@ -171,7 +171,7 @@ def run_staged(frames):
             i += n
         return eng.stats["flushes"] - f0
 
-    o1 = np.asarray(_parse_frame_rows(frames, True))
+    o1 = np.asarray(_parse_frame_rows(frames))
     t0 = time.perf_counter()
     flushes = (single(CHAIN_PARSE_WORKLOAD, frames, FRAME_ROW, PARSED_ROW)
                + single(CHAIN_DEQUANT_WORKLOAD, o1, PARSED_ROW, HDR_BYTES))

@@ -110,6 +110,7 @@ print('RESULT ' + json.dumps(out))
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"   # forced host devices, never the chip
     r = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=560)
     rows = []

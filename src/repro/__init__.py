@@ -1,10 +1,1 @@
-"""repro — RecoNIC-style RDMA compute offloading, reproduced on JAX.
-
-Importing the package installs the JAX forward-compat shims (see
-``repro.jax_compat``) so all entry points — tests, benchmarks, examples,
-subprocess workers — see the same mesh/shard_map API regardless of the
-installed JAX version.
-"""
-from repro import jax_compat as _jax_compat
-
-_jax_compat.install()
+"""repro — RecoNIC-style RDMA compute offloading, reproduced on JAX."""

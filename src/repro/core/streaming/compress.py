@@ -104,8 +104,7 @@ class GradEgressChain:
                  out_base: int, lc_peer: int = 0, depth: int = 32,
                  burst: int = 8, block: "LookasideBlock" = None,
                  scratch_base: int = None, scratch_size: int = None,
-                 pipeline_depth: int = 4, interpret: bool = True,
-                 name: str = "grad_egress"):
+                 pipeline_depth: int = 4, name: str = "grad_egress"):
         self.engine = engine
         self.data_peer = data_peer
         if block is None:
@@ -114,7 +113,7 @@ class GradEgressChain:
                                    scratch_size=scratch_size,
                                    eager_writeback=False,
                                    pipeline_depth=pipeline_depth)
-            register_chain_kernels(block, interpret=interpret)
+            register_chain_kernels(block)
         self.block = block
         self.ring = RXRing(engine, peer=block.peer, base=ring_base,
                            depth=depth, slot_bytes=HDR_BYTES)

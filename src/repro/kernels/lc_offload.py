@@ -56,10 +56,13 @@ the stream handlers, composable into ``Chain`` pipelines:
   sum of the words' raw bit patterns mod 2^24 (exact in the f32 pool) —
   the wire-integrity stamp of the compress→checksum gradient chain.
 
-Correctness contract: outputs are byte-identical to the host-side oracles
-in ``repro.kernels.ref`` on the same operand bytes (for the matmul, with
-a single K-block so the fp32 accumulation order matches the oracle's;
-for the quantizer, ``ref_quantize`` row-wise).
+Correctness contract: outputs are byte-identical to the oracles in
+``repro.kernels.ref`` run on the same backend over the same operand bytes
+(for the matmul, with a single K-block so the fp32 accumulation order
+matches the oracle's; for the quantizer, ``ref_quantize`` row-wise).
+Across backends they are not: a TPU's f32 division can move an int8 code
+by one step at a .5 tie, and an f32 matmul on the MXU multiplies in bf16
+passes. ``chip_smoke.py`` holds the chip to those bounds.
 """
 from __future__ import annotations
 
@@ -67,9 +70,11 @@ import functools
 from dataclasses import dataclass
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops
 from repro.kernels.packet_parser import HDR_BYTES, parse_packets
 from repro.kernels.quantize_stream import dequantize_stream, quantize_stream
 from repro.kernels.systolic_mm import systolic_mm
@@ -100,18 +105,17 @@ def _next_pow2(n: int) -> int:
 
 
 @functools.lru_cache(maxsize=None)
-def _stream_parser(bp: int, interpret: bool):
+def _stream_parser(bp: int):
     """Jitted parser per pow2 packet bucket: steady-state streaming must
     not re-trace the Pallas call per burst (the compute-side analogue of
     the descriptor executor's shape-bucket cache). Callers bucket
     ``bp`` to a power of two, so the unbounded cache stays a handful of
     entries."""
-    import jax
     return jax.jit(functools.partial(parse_packets, block_p=bp,
-                                     interpret=interpret))
+                                     interpret=ops._interpret()))
 
 
-def _parse_bucketed(pkts: np.ndarray, interpret: bool) -> np.ndarray:
+def _parse_bucketed(pkts: np.ndarray) -> np.ndarray:
     """Pad a packet batch to its pow2 bucket, parse with the cached
     jitted program, slice the live rows (row-wise kernel: padding never
     changes a live row's bytes)."""
@@ -119,20 +123,45 @@ def _parse_bucketed(pkts: np.ndarray, interpret: bool) -> np.ndarray:
     bp = _next_pow2(n)
     padded = np.zeros((bp, HDR_BYTES), np.uint8)
     padded[:n] = pkts
-    return _stream_parser(bp, interpret)(jnp.asarray(padded, jnp.uint8))[:n]
+    return _stream_parser(bp)(jnp.asarray(padded, jnp.uint8))[:n]
+
+
+#: an unaligned matmul dim up to this size is one whole block; a larger
+#: one is zero-padded to whole 128-blocks, so no block outgrows VMEM
+#: (whole blocks of at most 512^2 f32 words hold 7 MiB, double-buffered)
+MAX_WHOLE_DIM = 512
 
 
 def _mm_blocks(m: int, k: int, n: int):
-    """MXU-aligned blocks for aligned shapes, whole-dim blocks otherwise
-    (interpret mode has no VMEM bound; k < 128 keeps one K step, so the
+    """``((padded, block), ...)`` for m, k, n: MXU-aligned 128 blocks for
+    aligned dims and for unaligned dims above ``MAX_WHOLE_DIM`` (zero-
+    padded to a multiple of 128); one whole block for an unaligned dim up
+    to ``MAX_WHOLE_DIM`` (for k that keeps one K step, so the
     accumulation order — and hence the bytes — match ``ref_matmul``)."""
-    return (128 if m % 128 == 0 else m,
-            128 if n % 128 == 0 else n,
-            128 if k % 128 == 0 else k)
+    def one(d: int):
+        if d % 128 == 0 or d > MAX_WHOLE_DIM:
+            return -(-d // 128) * 128, 128
+        return d, d
+    return one(m), one(k), one(n)
+
+
+@functools.lru_cache(maxsize=None)
+def _mm_program(m: int, k: int, n: int):
+    """Jitted (M,K)x(K,N) systolic program per shape, padded to its
+    blocks (zero rows/cols add nothing to a live output)."""
+    (mp, bm), (kp, bk), (np_, bn) = _mm_blocks(m, k, n)
+
+    def run(x, y):
+        x = jnp.pad(x, ((0, mp - m), (0, kp - k)))
+        y = jnp.pad(y, ((0, kp - k), (0, np_ - n)))
+        z = systolic_mm(x, y, block_m=bm, block_n=bn, block_k=bk,
+                        interpret=ops._interpret())
+        return z[:m, :n]
+    return jax.jit(run)
 
 
 def lc_systolic_mm(ctx, remote_peer, rkey, a_addr, b_addr, out_addr,
-                   m, k, n, *, interpret: bool = True):
+                   m, k, n):
     """Offloaded (M,K)x(K,N) matmul: read A,B -> MXU systolic MM -> write C."""
     a_loc, b_loc = ctx.alloc(m * k), ctx.alloc(k * n)
     c_loc = ctx.alloc(m * n)
@@ -144,17 +173,14 @@ def lc_systolic_mm(ctx, remote_peer, rkey, a_addr, b_addr, out_addr,
             f"operand fetch failed: {ctx.failed[0].status.value}")
     x = jnp.asarray(ctx.load(a_loc, m * k).reshape(m, k))
     y = jnp.asarray(ctx.load(b_loc, k * n).reshape(k, n))
-    bm, bn, bk = _mm_blocks(m, k, n)
-    z = systolic_mm(x, y, block_m=bm, block_n=bn, block_k=bk,
-                    interpret=interpret)
+    z = _mm_program(m, k, n)(x, y)
     ctx.store(c_loc, np.asarray(z, np.float32).reshape(-1))
     ctx.write_remote(remote_peer, rkey, c_loc, out_addr, m * n)
     ctx.commit(wait=ctx.eager_writeback)
     return out_addr
 
 
-def lc_packet_parser(ctx, remote_peer, rkey, pkts_addr, n_pkts, out_addr,
-                     *, interpret: bool = True):
+def lc_packet_parser(ctx, remote_peer, rkey, pkts_addr, n_pkts, out_addr):
     """Offloaded RoCEv2 classifier: read headers -> parse -> write meta.
 
     Packets ride the float32 pool as byte values 0..255 (exact in fp32);
@@ -168,7 +194,7 @@ def lc_packet_parser(ctx, remote_peer, rkey, pkts_addr, n_pkts, out_addr,
         raise RuntimeError(
             f"packet fetch failed: {ctx.failed[0].status.value}")
     pkts = ctx.load(in_loc, nbytes).reshape(n_pkts, HDR_BYTES)
-    meta = _parse_bucketed(pkts, interpret)
+    meta = _parse_bucketed(pkts)
     ctx.store(out_loc, np.asarray(meta, np.float32).reshape(-1))
     ctx.write_remote(remote_peer, rkey, out_loc, out_addr, n_pkts * 4)
     ctx.commit(wait=ctx.eager_writeback)
@@ -208,8 +234,7 @@ def _scatter_rows(ctx, ring_base, out_peer, out_rkey, out_base, out_loc,
 
 
 def lc_packet_parser_stream(ctx, ring_peer, ring_rkey, ring_base,
-                            out_peer, out_rkey, out_base, spans, *,
-                            interpret: bool = True):
+                            out_peer, out_rkey, out_base, spans):
     """Streaming ``packet_parser`` handler (§IV-D): parse one sub-burst.
 
     A GENERATOR kernel — the two phases around the ``yield`` are what the
@@ -241,7 +266,7 @@ def lc_packet_parser_stream(ctx, ring_peer, ring_rkey, ring_base,
         raise RuntimeError(
             f"ring gather failed: {ctx.failed[0].status.value}")
     pkts = ctx.load(in_loc, nbytes).reshape(n_pkts, HDR_BYTES)
-    meta = _parse_bucketed(pkts, interpret)
+    meta = _parse_bucketed(pkts)
     ctx.store(meta_loc, np.asarray(meta, np.float32).reshape(-1))
     _scatter_rows(ctx, ring_base, out_peer, out_rkey, out_base, meta_loc,
                   spans, 4)
@@ -250,15 +275,14 @@ def lc_packet_parser_stream(ctx, ring_peer, ring_rkey, ring_base,
 
 
 @functools.lru_cache(maxsize=None)
-def _stream_quant(bp: int, interpret: bool):
+def _stream_quant(bp: int):
     """Jitted per pow2 row bucket like ``_stream_parser``: steady-state
     bulk streaming must not re-trace the Pallas quantizer per burst."""
-    import jax
     return jax.jit(functools.partial(quantize_stream, chunk=HDR_BYTES,
-                                     interpret=interpret))
+                                     interpret=ops._interpret()))
 
 
-def _quant_bucketed(x: np.ndarray, interpret: bool):
+def _quant_bucketed(x: np.ndarray):
     """Pad a (n, 64) payload batch to its pow2 row bucket, quantize with
     the cached jitted program, slice the live rows (each row quantizes
     independently with its own scale, so padding never changes a live
@@ -267,22 +291,20 @@ def _quant_bucketed(x: np.ndarray, interpret: bool):
     bp = _next_pow2(n)
     padded = np.zeros((bp, HDR_BYTES), np.float32)
     padded[:n] = x
-    q, s = _stream_quant(bp, interpret)(jnp.asarray(padded))
+    q, s = _stream_quant(bp)(jnp.asarray(padded))
     return q[:n], s[:n]
 
 
 @functools.lru_cache(maxsize=None)
-def _stream_dequant(bp: int, interpret: bool):
+def _stream_dequant(bp: int):
     """Jitted inverse of ``_stream_quant`` per pow2 row bucket: the KV
     serving client decompresses every fetched page with it, so
     steady-state decode must not re-trace the Pallas call per fetch."""
-    import jax
     return jax.jit(functools.partial(dequantize_stream,
-                                     interpret=interpret))
+                                     interpret=ops._interpret()))
 
 
-def _dequant_bucketed(q: np.ndarray, s: np.ndarray,
-                      interpret: bool) -> np.ndarray:
+def _dequant_bucketed(q: np.ndarray, s: np.ndarray) -> np.ndarray:
     """Pad (n, 64) int8 rows + their scales to the pow2 row bucket,
     dequantize with the cached jitted program, slice the live rows
     (row-wise kernel: padding never changes a live row's bytes)."""
@@ -292,14 +314,12 @@ def _dequant_bucketed(q: np.ndarray, s: np.ndarray,
     qpad[:n] = q
     spad = np.ones((bp, 1), np.float32)
     spad[:n] = s
-    out = _stream_dequant(bp, interpret)(jnp.asarray(qpad),
-                                         jnp.asarray(spad))
+    out = _stream_dequant(bp)(jnp.asarray(qpad), jnp.asarray(spad))
     return np.asarray(out[:n])
 
 
 def lc_quantize_stream(ctx, ring_peer, ring_rkey, ring_base,
-                       out_peer, out_rkey, out_base, spans, *,
-                       interpret: bool = True):
+                       out_peer, out_rkey, out_base, spans):
     """Streaming bulk-class handler: int8-quantize one sub-burst's
     payload slots in flight (the Streaming Compute block's gradient-
     compression role — ``quantize_stream`` per 64-lane slot chunk).
@@ -323,7 +343,7 @@ def lc_quantize_stream(ctx, ring_peer, ring_rkey, ring_base,
         raise RuntimeError(
             f"ring gather failed: {ctx.failed[0].status.value}")
     x = ctx.load(in_loc, nwords).reshape(n_slots, HDR_BYTES)
-    q, s = _quant_bucketed(x, interpret)
+    q, s = _quant_bucketed(x)
     rows = np.concatenate([np.asarray(q, np.float32),
                            np.asarray(s, np.float32)], axis=1)
     ctx.store(out_loc, rows.reshape(-1))
@@ -373,7 +393,7 @@ def _chain_stage_kernel(compute, out_row: int):
     through ``_service_grouped`` exactly like any handler, and its
     write-back region is the next stage's fetch source."""
     def stage(ctx, in_peer, in_rkey, in_base, out_peer, out_rkey,
-              out_base, spans, in_row, *, interpret: bool = True):
+              out_base, spans, in_row):
         n = sum(cnt for _, cnt in spans)
         nwords = n * in_row
         in_loc = ctx.alloc(nwords)
@@ -385,7 +405,7 @@ def _chain_stage_kernel(compute, out_row: int):
             raise RuntimeError(
                 f"chain stage gather failed: {ctx.failed[0].status.value}")
         rows = ctx.load(in_loc, nwords).reshape(n, in_row)
-        out = compute(rows, interpret)
+        out = compute(rows)
         ctx.store(out_loc, np.asarray(out, np.float32).reshape(-1))
         _scatter_rows(ctx, in_base, out_peer, out_rkey, out_base,
                       out_loc, spans, out_row, unit=in_row)
@@ -394,39 +414,34 @@ def _chain_stage_kernel(compute, out_row: int):
     return stage
 
 
-def _parse_frame_rows(rows: np.ndarray, interpret: bool) -> np.ndarray:
+def _parse_frame_rows(rows: np.ndarray) -> np.ndarray:
     """(n, FRAME_ROW) framed slots → (n, PARSED_ROW) [meta ‖ payload]:
     the header bytes run through the SAME cached Pallas parser as the
     stream handler; the quant payload passes through untouched for the
     next stage."""
     hdrs = np.asarray(rows[:, :HDR_BYTES], np.uint8)
-    meta = np.asarray(_parse_bucketed(hdrs, interpret), np.float32)
+    meta = np.asarray(_parse_bucketed(hdrs), np.float32)
     return np.concatenate([meta, np.asarray(rows[:, HDR_BYTES:],
                                             np.float32)], axis=1)
 
 
-def _dequant_trailing_rows(rows: np.ndarray, interpret: bool) -> np.ndarray:
+def _dequant_trailing_rows(rows: np.ndarray) -> np.ndarray:
     """(n, ≥QUANT_ROW) rows → (n, 64) f32: dequantize the TRAILING
     ``QUANT_ROW`` words (64 int8 lanes as f32 + the fp32 scale) with the
     cached ``_stream_dequant`` programs — leading words (e.g. the parse
     stage's meta) are pass-by metadata this stage ignores."""
     q = np.asarray(rows[:, -QUANT_ROW:-1], np.float32).astype(np.int8)
     s = np.asarray(rows[:, -1:], np.float32)
-    return _dequant_bucketed(q, s, interpret)
+    return _dequant_bucketed(q, s)
 
 
-def _compress_rows(rows: np.ndarray, interpret: bool) -> np.ndarray:
+def _compress_rows(rows: np.ndarray) -> np.ndarray:
     """(n, 64) f32 rows → (n, QUANT_ROW) [q ‖ scale] rows via the cached
     ``_stream_quant`` programs — byte parity with
     ``kops.compress(x, chunk=64)`` row-wise."""
-    q, s = _quant_bucketed(np.asarray(rows, np.float32), interpret)
+    q, s = _quant_bucketed(np.asarray(rows, np.float32))
     return np.concatenate([np.asarray(q, np.float32),
                            np.asarray(s, np.float32)], axis=1)
-
-
-def _checksum_stage_rows(rows: np.ndarray, interpret: bool) -> np.ndarray:
-    del interpret                # exact integer math, no Pallas program
-    return _checksum_rows(rows)
 
 
 #: workload id → (stage kernel compute fn, spec) of every chain-capable
@@ -442,41 +457,32 @@ CHAIN_STAGES = {
         "chain_compress", _compress_rows,
         ChainStageSpec(out_row=QUANT_ROW, fixed_in_row=HDR_BYTES)),
     CHAIN_CHECKSUM_WORKLOAD: (
-        "chain_checksum", _checksum_stage_rows,
+        "chain_checksum", _checksum_rows,
         ChainStageSpec(out_row=CSUM_ROW)),
 }
 
 
-def register_chain_kernels(block, interpret: bool = True,
-                           weight: int = 1):
+def register_chain_kernels(block, weight: int = 1):
     """Register the chain-capable stage kernels on a block, attaching
     each one's ``ChainStageSpec`` so ``register_chain`` can validate
     pipeline composition. Idempotent per block for already-registered
     ids is NOT supported (same contract as ``register``)."""
     for wid, (name, compute, spec) in CHAIN_STAGES.items():
-        fn = functools.partial(_chain_stage_kernel(compute, spec.out_row),
-                               interpret=interpret)
-        k = block.register(wid, fn, name, weight=weight)
+        k = block.register(wid, _chain_stage_kernel(compute, spec.out_row),
+                           name, weight=weight)
         k.stage_spec = spec
     return block
 
 
-def register_default_kernels(block, interpret: bool = True,
-                             weight: int = 1):
+def register_default_kernels(block, weight: int = 1):
     """Register the paper's example offload kernels on a block (the two
     ControlMsg kernels plus the dispatch plane's stream handler mix)."""
-    block.register(MM_WORKLOAD,
-                   functools.partial(lc_systolic_mm, interpret=interpret),
-                   "systolic_mm", weight=weight)
-    block.register(PARSER_WORKLOAD,
-                   functools.partial(lc_packet_parser, interpret=interpret),
-                   "packet_parser", weight=weight)
-    block.register(STREAM_PARSER_WORKLOAD,
-                   functools.partial(lc_packet_parser_stream,
-                                     interpret=interpret),
+    block.register(MM_WORKLOAD, lc_systolic_mm, "systolic_mm",
+                   weight=weight)
+    block.register(PARSER_WORKLOAD, lc_packet_parser, "packet_parser",
+                   weight=weight)
+    block.register(STREAM_PARSER_WORKLOAD, lc_packet_parser_stream,
                    "packet_parser_stream", weight=weight)
-    block.register(STREAM_QUANT_WORKLOAD,
-                   functools.partial(lc_quantize_stream,
-                                     interpret=interpret),
+    block.register(STREAM_QUANT_WORKLOAD, lc_quantize_stream,
                    "quantize_stream", weight=weight)
     return block

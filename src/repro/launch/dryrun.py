@@ -1,8 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST precede every other import: jax locks the device
-# count at first init. (Only the dry-run forces 512 placeholder devices —
-# tests and benchmarks see the real single CPU device.)
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST precede every other import: jax locks the platform
+# and device count at first init. (Only the dry-run forces 512 placeholder
+# host devices — tests and benchmarks see the real single CPU device.)
 if os.environ.get("DRYRUN_DEVICES"):       # test hook (jax not imported yet)
     os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                                + os.environ["DRYRUN_DEVICES"])

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import init_caches, init_params
 from repro.serve.serve_step import decode_step, prefill_step
 
@@ -68,6 +69,7 @@ def main():
     ap.add_argument("--gen-len", type=int, default=16)
     ap.add_argument("--out", default="")
     args = ap.parse_args()
+    enable_compile_cache()
     res = run(args.arch, args.requests, args.prompt_len, args.gen_len,
               max_seq=args.prompt_len + args.gen_len + 8)
     print(json.dumps(res, indent=1))

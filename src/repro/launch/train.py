@@ -24,6 +24,7 @@ import numpy as np
 from repro.checkpoint.checkpoint import CheckpointManager
 from repro.configs.base import TrainConfig
 from repro.configs.registry import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.rdma.cost_model import TPU_V5E
 from repro.core.rdma.doorbell import choose_bucket_bytes, plan_buckets
 from repro.core.streaming.classifier import (TrafficClass, TrafficRouter,
@@ -122,6 +123,7 @@ def main():
     ap.add_argument("--data-cycle", type=int, default=0)
     ap.add_argument("--out", default="")
     args = ap.parse_args()
+    enable_compile_cache()
     res = run(args.arch, args.steps, args.batch, args.seq, args.ckpt_dir,
               args.resume, lr=args.lr, microbatches=args.microbatches,
               data_cycle=args.data_cycle)

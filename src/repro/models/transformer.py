@@ -208,24 +208,6 @@ def set_remat_policy(name: str) -> None:
     _REMAT_POLICY = name
 
 
-_SCAN_LAYERS = True
-
-
-def set_layer_scan(on: bool) -> None:
-    """Toggle ``lax.scan`` over layers vs an unrolled python loop.
-
-    The unrolled form exists for contexts where XLA cannot partition a
-    loop — notably partial-auto ``shard_map`` bodies on the legacy (0.4.x)
-    SPMD partitioner, which aborts on control flow inside a mixed
-    manual/auto region (see ``repro.jax_compat``)."""
-    global _SCAN_LAYERS
-    _SCAN_LAYERS = on
-
-
-def layer_scan_enabled() -> bool:
-    return _SCAN_LAYERS
-
-
 def _scan_stack(layers: dict, cfg: ModelConfig, x, positions, windows,
                 caches, mrope_positions, enc_out=None, causal=True,
                 remat=False, sequence_parallel=False):
@@ -255,14 +237,7 @@ def _scan_stack(layers: dict, cfg: ModelConfig, x, positions, windows,
                   else jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
         body = jax.checkpoint(body, policy=policy)
     xs = (layers, windows, caches) if has_cache else (layers, windows)
-    if _SCAN_LAYERS:
-        x, outs = jax.lax.scan(body, x, xs)
-    else:
-        per_layer = []
-        for i in range(int(windows.shape[0])):
-            x, out = body(x, jax.tree.map(lambda a: a[i], xs))
-            per_layer.append(out)
-        outs = jax.tree.map(lambda *ls: jnp.stack(ls), *per_layer)
+    x, outs = jax.lax.scan(body, x, xs)
     if has_cache:
         new_caches, auxs = outs
         return x, new_caches, jnp.sum(auxs)

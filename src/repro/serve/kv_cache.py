@@ -88,7 +88,7 @@ def packed_page_words(page_elems: int) -> int:
     return page_elems // PAGE_CHUNK + page_elems // 2
 
 
-def quant_pack_page(x: np.ndarray, interpret: bool = True) -> np.ndarray:
+def quant_pack_page(x: np.ndarray) -> np.ndarray:
     """Quantize-pack one logical page into its wire format.
 
     ``x`` (page_elems,) f32 -> (packed_page_words,) f32 pool words:
@@ -100,14 +100,13 @@ def quant_pack_page(x: np.ndarray, interpret: bool = True) -> np.ndarray:
     ``ref.ref_quantize`` row-wise)."""
     from repro.kernels.lc_offload import _quant_bucketed
     x = np.asarray(x, np.float32).reshape(-1, PAGE_CHUNK)
-    q, s = _quant_bucketed(x, interpret)
+    q, s = _quant_bucketed(x)
     pairs = (np.asarray(q, np.int64) + 128).reshape(-1, 2)
     packed = (pairs[:, 0] * 256 + pairs[:, 1]).astype(np.float32)
     return np.concatenate([np.asarray(s, np.float32).reshape(-1), packed])
 
 
-def quant_unpack_page(words: np.ndarray, page_elems: int,
-                      interpret: bool = True) -> np.ndarray:
+def quant_unpack_page(words: np.ndarray, page_elems: int) -> np.ndarray:
     """Inverse of ``quant_pack_page``: (packed_page_words,) pool words
     -> (page_elems,) dequantized f32, through the cached jitted
     ``dequantize_stream`` program (bit-identical to
@@ -118,7 +117,7 @@ def quant_unpack_page(words: np.ndarray, page_elems: int,
     pw = np.rint(np.asarray(words[n_chunks:], np.float64)).astype(np.int64)
     q = np.stack([pw // 256 - 128, pw % 256 - 128], axis=1)
     q = q.reshape(n_chunks, PAGE_CHUNK).astype(np.int8)
-    return _dequant_bucketed(q, s, interpret).reshape(-1)
+    return _dequant_bucketed(q, s).reshape(-1)
 
 
 @dataclass
@@ -144,13 +143,12 @@ class PagedKVPool:
 
     def __init__(self, engine, peer: int, page_elems: int,
                  max_pages: int, dtype=np.float32,
-                 compressed: bool = False, interpret: bool = True):
+                 compressed: bool = False):
         self.engine = engine
         self.peer = peer
         self.page_elems = page_elems
         self.dtype = np.dtype(dtype)
         self.compressed = compressed
-        self.interpret = interpret
         if compressed:
             self.page_words = packed_page_words(page_elems)
             self.page_nbytes = (page_elems
@@ -185,14 +183,14 @@ class PagedKVPool:
         recompile)."""
         data = np.asarray(data, np.float32).reshape(-1)
         if self.compressed:
-            data = quant_pack_page(data, self.interpret)
+            data = quant_pack_page(data)
         self.pool.write(page.mr, data)
 
     def read_page(self, page: Page) -> np.ndarray:
         """Logical page contents (dequantized for compressed pools)."""
         raw = self.pool.read(page.mr)
         if self.compressed:
-            return quant_unpack_page(raw, self.page_elems, self.interpret)
+            return quant_unpack_page(raw, self.page_elems)
         return raw
 
     def read_page_raw(self, page: Page) -> np.ndarray:
@@ -479,8 +477,7 @@ class RemoteKVClient:
             rows = raw.reshape(len(tk.pages), self.pool.page_words)
             if self.pool.compressed:
                 rows = np.stack([
-                    quant_unpack_page(r, self.pool.page_elems,
-                                      self.pool.interpret)
+                    quant_unpack_page(r, self.pool.page_elems)
                     for r in rows])
             tk.data = rows
             led["pages_fetched"] += len(tk.pages)

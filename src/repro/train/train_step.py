@@ -33,10 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import jax_compat
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core.rdma.doorbell import plan_buckets
-from repro.models import transformer
 from repro.models.sharding import param_specs
 from repro.models.transformer import loss_fn
 from repro.train.optimizer import (
@@ -70,13 +68,7 @@ def _microbatch_grads(params, cfg: ModelConfig, batch: dict,
 
     zero = (jnp.float32(0),
             jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params))
-    if transformer.layer_scan_enabled():
-        (loss_sum, g_sum), _ = jax.lax.scan(body, zero, micro)
-    else:  # control-flow-free tracing mode (see make_bucketed_train_step)
-        acc = zero
-        for i in range(n):
-            acc, _ = body(acc, jax.tree.map(lambda x: x[i], micro))
-        loss_sum, g_sum = acc
+    (loss_sum, g_sum), _ = jax.lax.scan(body, zero, micro)
     inv = 1.0 / n
     return loss_sum * inv, jax.tree.map(lambda g: g * inv, g_sum)
 
@@ -241,21 +233,6 @@ def make_bucketed_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh,
         return loss, new_params, new_opt, residuals
 
     batch_spec = P(dp_axes if len(dp_axes) > 1 else dp_axes[0])
-
-    # Legacy (0.4.x) XLA aborts on control flow inside a partial-auto
-    # shard_map body (manual DP axes + auto 'model'); trace the body with
-    # the layer/microbatch scans unrolled there instead.
-    partial_auto = set(dp_axes) != set(mesh.axis_names)
-    if partial_auto and jax_compat.legacy_shard_map():
-        inner_step = local_step
-
-        def local_step(*args):  # noqa: F811 — deliberate rebinding
-            prev = transformer.layer_scan_enabled()
-            transformer.set_layer_scan(False)
-            try:
-                return inner_step(*args)
-            finally:
-                transformer.set_layer_scan(prev)
 
     def step(params, opt_state, batch, residuals):
         return jax.shard_map(

@@ -180,8 +180,8 @@ class TestIngressChainParity:
         eng, _, ring, disp, _, (s1, s2) = _ingress_setup()
         frames = _frames(13)
         _drive(ring, disp, frames, DEPTH)
-        o1 = _parse_frame_rows(frames, True)
-        o2 = _dequant_trailing_rows(o1, True)
+        o1 = _parse_frame_rows(frames)
+        o2 = _dequant_trailing_rows(o1)
         # slots are reused across windows: seqs 0..4 were overwritten by
         # 8..12, so rows 5..12 are the live, checkable set
         live = list(range(5, 13))
@@ -235,7 +235,7 @@ class TestIngressChainParity:
             _drive(ring, disp, rows, depth)
             return eng.stats["flushes"] - f0
 
-        o1 = np.asarray(_parse_frame_rows(frames, True))
+        o1 = np.asarray(_parse_frame_rows(frames))
         staged = (single_stage_flushes(CHAIN_PARSE_WORKLOAD, frames,
                                        FRAME_ROW, PARSED_ROW)
                   + single_stage_flushes(CHAIN_DEQUANT_WORKLOAD, o1,
@@ -347,8 +347,8 @@ class TestChainChaos:
         eng, _, ring, disp, _, (s1, s2) = _ingress_setup(eng=eng)
         frames = _frames(13)
         _drive(ring, disp, frames, DEPTH)
-        o1 = _parse_frame_rows(frames, True)
-        o2 = _dequant_trailing_rows(o1, True)
+        o1 = _parse_frame_rows(frames)
+        o2 = _dequant_trailing_rows(o1)
         live = list(range(5, 13))
         np.testing.assert_array_equal(
             _stage_rows(eng, s1, PARSED_ROW, DEPTH, live),
@@ -424,6 +424,7 @@ print("ICI_CHAIN_OK", led["stage_invocations"])
         env = dict(os.environ)
         env["PYTHONPATH"] = SRC
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+        env["JAX_PLATFORMS"] = "cpu"   # forced host devices, never the chip
         r = subprocess.run([sys.executable, "-c", code], env=env,
                            capture_output=True, text=True, timeout=560)
         assert "ICI_CHAIN_OK" in r.stdout, r.stdout + r.stderr

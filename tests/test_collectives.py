@@ -285,6 +285,7 @@ print('ICI_COLL_OK')
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"   # forced host devices, never the chip
     r = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=560)
     assert "ICI_COLL_OK" in r.stdout, r.stdout + r.stderr

@@ -102,7 +102,8 @@ def test_attention_bf16():
 # quantize stream
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("n,chunk", [(4, 64), (16, 128), (1, 256)])
+@pytest.mark.parametrize("n,chunk", [(4, 64), (16, 128), (1, 256),
+                                     (1030, 64), (70, 1024)])
 def test_quantize_roundtrip_error_bound(n, chunk):
     x = randn((n, chunk))
     q, s = quantize_stream(x, chunk=chunk, interpret=True)

@@ -69,7 +69,8 @@ def _roce_packets(n_pkts):
 
 class TestOffloadParity:
     @pytest.mark.parametrize("m,k,n", [(8, 16, 12), (16, 32, 8),
-                                       (4, 128, 4)])
+                                       (4, 128, 4),
+                                       (520, 8, 4)])   # m padded to 640
     def test_systolic_mm_byte_identical_to_host_reference(self, m, k, n):
         eng, blk = _engine()
         A, B, mr, (a, b, out) = _place_mm(eng, m, k, n)
@@ -152,6 +153,7 @@ print("ICI_LC_OK", eng.stats["lc_wqes"])
         env = dict(os.environ)
         env["PYTHONPATH"] = SRC
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+        env["JAX_PLATFORMS"] = "cpu"   # forced host devices, never the chip
         r = subprocess.run([sys.executable, "-c", code], env=env,
                            capture_output=True, text=True, timeout=560)
         assert "ICI_LC_OK" in r.stdout, r.stdout + r.stderr

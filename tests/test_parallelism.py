@@ -17,6 +17,7 @@ def _run_py(code: str, devices: int = 8, timeout: int = 560):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"   # forced host devices, never the chip
     return subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=timeout)
 

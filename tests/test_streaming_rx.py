@@ -328,6 +328,7 @@ print("ICI_STREAM_OK", eng.stats["lc_pipeline"]["head"])
         env = dict(os.environ)
         env["PYTHONPATH"] = SRC
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+        env["JAX_PLATFORMS"] = "cpu"   # forced host devices, never the chip
         r = subprocess.run([sys.executable, "-c", code], env=env,
                            capture_output=True, text=True, timeout=560)
         assert "ICI_STREAM_OK" in r.stdout, r.stdout + r.stderr

@@ -229,3 +229,27 @@ def test_data_pipeline_determinism_and_skip_ahead():
     np.testing.assert_array_equal(direct["tokens"], resumed["tokens"])
     np.testing.assert_array_equal(p.batch_at(0)["labels"][:, :-1],
                                   p.batch_at(0)["tokens"][:, 1:])
+
+
+@pytest.mark.parametrize("env_dir", ["", "/elsewhere/jax-cache"])
+def test_compile_cache_location(monkeypatch, env_dir):
+    """Entry points keep JAX's compile cache where the environment says,
+    and otherwise at one fixed path inside the checkout."""
+    from repro.launch import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_dir:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert got == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            assert compile_cache.enable_compile_cache() == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -260,6 +260,7 @@ print("ICI_PARITY_OK", ici.stats["compiles"])
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"   # forced host devices, never the chip
     r = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=560)
     assert "ICI_PARITY_OK" in r.stdout, r.stdout + r.stderr

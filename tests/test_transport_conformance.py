@@ -445,6 +445,7 @@ print("ICI_QDMA_OK", ici.stats["qdma_compiles"])
         env = dict(os.environ)
         env["PYTHONPATH"] = SRC
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        env["JAX_PLATFORMS"] = "cpu"   # forced host devices, never the chip
         r = subprocess.run([sys.executable, "-c", code], env=env,
                            capture_output=True, text=True, timeout=560)
         assert "ICI_QDMA_OK" in r.stdout, r.stdout + r.stderr
@@ -594,6 +595,7 @@ print("ICI_RELIABILITY_OK", rel["retransmits"])
         env = dict(os.environ)
         env["PYTHONPATH"] = SRC
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        env["JAX_PLATFORMS"] = "cpu"   # forced host devices, never the chip
         r = subprocess.run([sys.executable, "-c", code], env=env,
                            capture_output=True, text=True, timeout=560)
         assert "ICI_RELIABILITY_OK" in r.stdout, r.stdout + r.stderr
