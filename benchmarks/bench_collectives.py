@@ -48,7 +48,7 @@ def run_ring(steps: int):
     coll.all_reduce(_shards(rng, N_PEERS, WORDS))        # warm-up
     c0 = eng.stats["transport"]["compiles"]
     q0 = eng.stats["transport"]["qdma_compiles"]
-    w0 = coll.stats["wire_words"]
+    w0 = coll.stats["wire_bytes"]
     parity = True
     for _ in range(steps):
         shards = _shards(rng, N_PEERS, WORDS)
@@ -56,7 +56,7 @@ def run_ring(steps: int):
         want = np.sum(shards, axis=0)
         parity &= all(np.array_equal(got[p][:WORDS], want)
                       for p in range(N_PEERS))
-    wire = coll.stats["wire_words"] - w0
+    wire = (coll.stats["wire_bytes"] - w0) // 4     # f32 pool words
     ideal = steps * ideal_wire_words("ring", N_PEERS, WORDS)
     return {
         "steps": steps,

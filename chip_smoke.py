@@ -477,8 +477,8 @@ def run_four_chips(seed: int, words: int = BUCKET_WORDS) -> None:
         on_ici,
         f"one bucket of {words} f32 words ({4 * words} B) per peer, "
         f"{led['rounds']} rounds",
-        check(led["wire_words"] == ideal_wire_words("ring", n, words),
-              f"{led['wire_words']} wire words = the ring's ideal"),
+        check(led["wire_bytes"] == 4 * ideal_wire_words("ring", n, words),
+              f"{led['wire_bytes'] // 4} wire words = the ring's ideal"),
         check(all(np.array_equal(g, want) for g in got),
               "every peer's sum equals the numpy sum exactly"),
         check(all(np.array_equal(row, want) for row in ref),

@@ -400,11 +400,11 @@ def main():
     print(f"COLLECTIVES: ring all-reduce of 2 buckets x 256 words over "
           f"4 peers: {led['rounds']} rounds in {led['flushes']} flushes "
           f"({led['overlapped_flushes']} overlapped), "
-          f"{led['wire_words']} wire words "
+          f"{led['wire_bytes'] // 4} wire words "
           f"(ideal {2 * ideal_wire_words('ring', 4, 256)}), "
           f"parity={parity}")
     assert parity and led["overlapped_flushes"] > 0
-    assert led["wire_words"] == 2 * ideal_wire_words("ring", 4, 256)
+    assert led["wire_bytes"] == 4 * 2 * ideal_wire_words("ring", 4, 256)
 
     # -- AUTOTUNE: the transport tunes its own knobs -----------------------
     # Every knob above (ring_burst=32, pipeline_depth, flush_budget, the

@@ -28,7 +28,6 @@ staged over the "PCIe" path, dev_mem regions live in the device pool.
 from __future__ import annotations
 
 import itertools
-import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -37,6 +36,7 @@ from repro.core.rdma.autotune import TransportTuning
 from repro.core.rdma.doorbell import coalesce_plan, schedule_plan
 from repro.core.rdma.reliability import (FaultInjector, ReliabilityConfig,
                                          ReliabilityLayer)
+from repro.core.rdma.trace import span
 from repro.core.rdma.transport import make_transport
 from repro.core.rdma.verbs import (
     CQE, CQEStatus, MemoryRegion, Opcode, ONE_SIDED, Placement, QPState,
@@ -123,8 +123,9 @@ class RDMAEngine:
         # qp_num (the fairness ledger the cost model reads); "lc_service"
         # is the subset on Lookaside-Compute-owned QPs (host-vs-compute
         # contention on the shared engine); "qp_bytes" ledgers completed
-        # payload bytes per QP; "qp_latency_us" histograms doorbell-to-
-        # execution latency per QP in pow2-µs buckets.
+        # payload bytes per QP. Time per flush stage is not a stat: it
+        # is recorded as ``rdma.flush*`` spans (``trace.py``) while the
+        # profiler collects.
         # "lc_pipeline" is the Lookaside multi-invocation pipeline's
         # head/tail credit ledger (admitted vs finalized invocations,
         # credit waits, flushes that overlapped a fetch with an earlier
@@ -139,7 +140,7 @@ class RDMAEngine:
         self.stats = {"doorbells": 0, "wqes": 0, "cqes": 0, "errors": 0,
                       "coalesced_wqes": 0, "flushes": 0,
                       "qp_service": {}, "lc_service": {}, "lc_wqes": 0,
-                      "qp_bytes": {}, "qp_latency_us": {},
+                      "qp_bytes": {},
                       "lc_pipeline": {}, "dispatch": {}, "kv_serve": {},
                       "collectives": {}, "autotune": {},
                       "transport": self.transport.stats}
@@ -215,12 +216,7 @@ class RDMAEngine:
         windows into a single scheduled transport batch. A non-deferred
         ring flushes immediately (serving any other armed QPs too — the
         engine is shared, exactly the paper's contention point)."""
-        prev = max(qp.sq_doorbell, qp.sq_cidx)
         qp.sq_doorbell = qp.sq_pidx if pidx is None else pidx
-        newly = max(0, qp.sq_doorbell - prev)
-        if newly:                       # stamp for the latency histogram
-            now = time.perf_counter()
-            qp.arm_times.extend([now] * newly)
         if qp not in self._armed:
             self._armed.append(qp)
         self.stats["doorbells"] += 1
@@ -346,80 +342,86 @@ class RDMAEngine:
                            and (qp.pending_count
                                 or relia.pending(qp.qp_num))]
             return {}
-        order, counts = schedule_plan(
-            [(qp.qp_num, wqes) for qp, wqes in windows],
-            scheduler=self.scheduler,
-            weights={qp.qp_num: qp.weight for qp, _ in windows},
-            budget=self.flush_budget,
-            qp_window=self.qp_window,
-            state=self._sched_state,
-            promote_after=self.promote_after,
-            # snapshots are budget-truncated; drr needs the true depth to
-            # tell "window drained" from "snapshot exhausted"
-            backlog=backlog)
+        with span("rdma.flush", flush=self.stats["flushes"],
+                  qps=len(windows)) as sp:
+            return self._run_windows(sp, relia, windows, retx_len, backlog)
+
+    def _run_windows(self, sp, relia, windows: List[tuple],
+                     retx_len: Dict[int, int],
+                     backlog: Dict[int, int]) -> Dict[int, int]:
+        """The body of ``flush_doorbells`` once there are windows to run:
+        schedule, admit and coalesce, one transport dispatch, then the
+        service ledger and CQE delivery. ``sp`` is the flush's span."""
+        with span("rdma.flush.schedule"):
+            order, counts = schedule_plan(
+                [(qp.qp_num, wqes) for qp, wqes in windows],
+                scheduler=self.scheduler,
+                weights={qp.qp_num: qp.weight for qp, _ in windows},
+                budget=self.flush_budget,
+                qp_window=self.qp_window,
+                state=self._sched_state,
+                promote_after=self.promote_after,
+                # snapshots are budget-truncated; drr needs the true depth
+                # to tell "window drained" from "snapshot exhausted"
+                backlog=backlog)
+        sp.set(wqes=len(order))
         by_num = {qp.qp_num: qp for qp, _ in windows}
         plan: List[tuple] = []
         completions: List[tuple] = []   # (qp, CQE, remote) after transport
-        if relia is not None:
-            for qp_num, entry in order:
-                relia.process(by_num[qp_num], entry, plan, completions)
-        else:
-            for qp_num, wqe in order:
-                self._admit(by_num[qp_num], wqe, plan, completions)
-
-        # Coalesce adjacent contiguous transfers (the descriptor-level
-        # doorbell batching), then ONE pre-compiled dispatch for the batch.
-        if self.coalesce:
-            merged = coalesce_plan(plan)
-            saved = len(plan) - len(merged)
-            self.stats["coalesced_wqes"] += saved
-            self.transport.stats["coalesced_wqes"] += saved
-            plan = merged
+        with span("rdma.flush.admit") as admit:
+            if relia is not None:
+                for qp_num, entry in order:
+                    relia.process(by_num[qp_num], entry, plan, completions)
+            else:
+                for qp_num, wqe in order:
+                    self._admit(by_num[qp_num], wqe, plan, completions)
+            # Coalesce adjacent contiguous transfers (the descriptor-level
+            # doorbell batching), then ONE pre-compiled dispatch.
+            saved = 0
+            if self.coalesce:
+                merged = coalesce_plan(plan)
+                saved = len(plan) - len(merged)
+                self.stats["coalesced_wqes"] += saved
+                self.transport.stats["coalesced_wqes"] += saved
+                plan = merged
+            admit.set(coalesced=saved)
         self.transport.execute_batch(plan)
 
-        served = [n for n in counts.values() if n]
-        if len(served) > 1:
-            self.transport.stats["interleaved_batches"] += 1
-        now = time.perf_counter()
-        for qp_num, n in counts.items():
-            if n:
-                qp = by_num[qp_num]
-                # replayed picks never touch the SQ (the reliability
-                # layer owns them); only freshly scheduled WQEs retire
-                # and stamp the doorbell-latency histogram. Service is
-                # charged in FULL — retransmits bill their owner.
-                n_new = n - min(n, retx_len.get(qp_num, 0))
-                hist = self.stats["qp_latency_us"].setdefault(qp_num, {})
-                for _ in range(n_new):
-                    t0 = qp.arm_times.popleft() if qp.arm_times else now
-                    us = (now - t0) * 1e6
-                    bucket = 1           # pow2-µs ceiling bucket
-                    while bucket < us:
-                        bucket <<= 1
-                    hist[bucket] = hist.get(bucket, 0) + 1
-                qp.retire(n_new)
-                self.stats["qp_service"][qp_num] = (
-                    self.stats["qp_service"].get(qp_num, 0) + n)
-                if qp.lc:
-                    self.stats["lc_wqes"] += n
-                    self.stats["lc_service"][qp_num] = (
-                        self.stats["lc_service"].get(qp_num, 0) + n)
-        self.stats["wqes"] += len(order)
-        self.stats["flushes"] += 1
+        with span("rdma.flush.complete"):
+            served = [n for n in counts.values() if n]
+            if len(served) > 1:
+                self.transport.stats["interleaved_batches"] += 1
+            for qp_num, n in counts.items():
+                if n:
+                    qp = by_num[qp_num]
+                    # replayed picks never touch the SQ (the reliability
+                    # layer owns them); only freshly scheduled WQEs
+                    # retire. Service is charged in FULL — retransmits
+                    # bill their owner.
+                    qp.retire(n - min(n, retx_len.get(qp_num, 0)))
+                    self.stats["qp_service"][qp_num] = (
+                        self.stats["qp_service"].get(qp_num, 0) + n)
+                    if qp.lc:
+                        self.stats["lc_wqes"] += n
+                        self.stats["lc_service"][qp_num] = (
+                            self.stats["lc_service"].get(qp_num, 0) + n)
+            self.stats["wqes"] += len(order)
+            self.stats["flushes"] += 1
 
-        for q, cqe, remote in completions:
-            self.stats["qp_bytes"][q.qp_num] = (
-                self.stats["qp_bytes"].get(q.qp_num, 0) + cqe.byte_len)
-            self._complete(q, cqe)
-            if remote is not None:
-                self._complete(*remote)
-        self._armed = [qp for qp in self._armed
-                       if qp.pending_count
-                       or (relia is not None and relia.pending(qp.qp_num))]
-        if relia is not None:
-            # refresh the pressure gauge post-delivery: the shedder and
-            # benches read end-of-flush pressure, not start-of-flush
-            relia.stats["retx_pressure"] = relia.outstanding()
+            for q, cqe, remote in completions:
+                self.stats["qp_bytes"][q.qp_num] = (
+                    self.stats["qp_bytes"].get(q.qp_num, 0) + cqe.byte_len)
+                self._complete(q, cqe)
+                if remote is not None:
+                    self._complete(*remote)
+            self._armed = [qp for qp in self._armed
+                           if qp.pending_count
+                           or (relia is not None
+                               and relia.pending(qp.qp_num))]
+            if relia is not None:
+                # refresh the pressure gauge post-delivery: the shedder
+                # and benches read end-of-flush pressure
+                relia.stats["retx_pressure"] = relia.outstanding()
         return counts
 
     def _admit(self, qp: QueuePair, wqe: WQE, plan: List[tuple],

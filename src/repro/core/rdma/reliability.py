@@ -255,7 +255,6 @@ class ReliabilityLayer:
                     self._flush_cqe(qp, wqe)
                 qp.retire(n)
                 qp.sq_pidx = qp.sq_doorbell = qp.sq_cidx
-                qp.arm_times.clear()
 
     def _flush_cqe(self, qp: QueuePair, wqe: WQE) -> None:
         self.stats["flushed_wqes"] += 1

@@ -37,7 +37,10 @@ never participates — only the collective program touches its buffer row.
 Both transports expose a ``stats`` dict (dispatches, wqes, cache hits and
 misses, compiles, coalesced WQEs, interleaved multi-QP batches, and the
 ``qdma_*`` staging counters) that the engine threads into its own stats
-and the simulator's cost model reads via ``predict_from_stats``.
+and the simulator's cost model reads via ``predict_from_stats``. While the
+profiler collects, each dispatch and each host copy is also a span
+(``trace.py``) carrying the bytes it uploaded or read back:
+``rdma.transport.execute``, ``rdma.qdma.h2d``, ``rdma.qdma.d2h``.
 """
 from __future__ import annotations
 
@@ -50,6 +53,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.rdma.autotune import BucketLearner
+from repro.core.rdma.trace import span
 
 PEER_AXIS = "peers"
 
@@ -379,6 +383,37 @@ class _TransportBase:
             new += 1
         return new
 
+    def execute_batch(self, plan: Sequence[tuple]) -> None:
+        """plan: iterable of (kind, src, dst, src_addr, dst_addr, length).
+        One pre-compiled dispatch per doorbell; plan data rides as an
+        operand (descriptor table), never as a static argument."""
+        if not plan:
+            return
+        with span("rdma.transport.execute", wqes=len(plan)) as sp:
+            desc, chunk = pack_descriptors(plan, self.pool.shape[1])
+            self._run_descriptors(desc, chunk)
+            sp.set(slots=desc.shape[0], chunk=chunk, h2d_bytes=desc.nbytes)
+        self._account((desc.shape[0], chunk), len(plan),
+                      max_len=max((e[5] for e in plan), default=0))
+
+    def host_read(self, peer: int, addr: int, length: int):
+        """QDMA C2H: ``length`` words of ``peer``'s row, on the host."""
+        with span("rdma.qdma.d2h") as sp:
+            out = jax.device_get(self.pool[peer, addr:addr + length])
+            sp.set(bytes=out.nbytes)
+        return out
+
+    def host_write(self, peer: int, addr: int, data) -> None:
+        """Descriptor-ized QDMA H2C: data is padded to a pow2 chunk bucket
+        and scattered by ``_exec_staging`` with (peer, addr, length) as
+        operands — new data *lengths* only recompile on a new bucket."""
+        with span("rdma.qdma.h2d") as sp:
+            staged, desc, chunk = pack_staging(
+                data, addr, peer, self.pool.shape[1], self.pool.dtype)
+            self._run_staging(staged, desc, chunk)
+            sp.set(bytes=staged.nbytes)
+        self._account_qdma(chunk)
+
     def _account_qdma(self, chunk: int) -> None:
         if chunk in self._seen_qdma_buckets:
             self.stats["qdma_cache_hits"] += 1
@@ -405,16 +440,9 @@ class LocalTransport(_TransportBase):
     def _run_descriptors(self, desc: jax.Array, chunk: int) -> None:
         self.pool = _exec_descriptors_local(self.pool, desc, chunk)
 
-    def execute_batch(self, plan: Sequence[tuple]) -> None:
-        """plan: iterable of (kind, src, dst, src_addr, dst_addr, length).
-        One pre-compiled dispatch per doorbell; plan data rides as an
-        operand (descriptor table), never as a static argument."""
-        if not plan:
-            return
-        desc, chunk = pack_descriptors(plan, self.pool.shape[1])
-        self._run_descriptors(desc, chunk)
-        self._account((desc.shape[0], chunk), len(plan),
-                      max_len=max((e[5] for e in plan), default=0))
+    def _run_staging(self, staged: jax.Array, desc: jax.Array,
+                     chunk: int) -> None:
+        self.pool = _exec_staging(self.pool, staged, desc, chunk)
 
     def execute_batch_static(self, plan: Sequence[tuple]) -> None:
         """Seed executor: plan baked in as a static jit argument (one XLA
@@ -424,18 +452,6 @@ class LocalTransport(_TransportBase):
         self.pool = _run_plan_local_static(self.pool, tuple(plan))
         self.stats["dispatches"] += 1
         self.stats["wqes"] += len(plan)
-
-    def host_read(self, peer: int, addr: int, length: int):
-        return jax.device_get(self.pool[peer, addr:addr + length])
-
-    def host_write(self, peer: int, addr: int, data) -> None:
-        """Descriptor-ized QDMA H2C: data is padded to a pow2 chunk bucket
-        and scattered by ``_exec_staging`` with (peer, addr, length) as
-        operands — new data *lengths* only recompile on a new bucket."""
-        staged, desc, chunk = pack_staging(
-            data, addr, peer, self.pool.shape[1], self.pool.dtype)
-        self.pool = _exec_staging(self.pool, staged, desc, chunk)
-        self._account_qdma(chunk)
 
     def host_write_static(self, peer: int, addr: int, data) -> None:
         """Seed QDMA path: data shape is the jit cache key (one XLA
@@ -464,14 +480,10 @@ class ICITransport(_TransportBase):
         with jax.set_mesh(self.mesh):
             self.pool = self._program(self.pool, desc, chunk)
 
-    def execute_batch(self, plan: Sequence[tuple]) -> None:
-        """plan: iterable of (kind, src, dst, src_addr, dst_addr, length)."""
-        if not plan:
-            return
-        desc, chunk = pack_descriptors(plan, self.pool.shape[1])
-        self._run_descriptors(desc, chunk)
-        self._account((desc.shape[0], chunk), len(plan),
-                      max_len=max((e[5] for e in plan), default=0))
+    def _run_staging(self, staged: jax.Array, desc: jax.Array,
+                     chunk: int) -> None:
+        with jax.set_mesh(self.mesh):
+            self.pool = _exec_staging(self.pool, staged, desc, chunk)
 
     def execute_batch_static(self, plan: Sequence[tuple]) -> None:
         """Seed executor (static plan -> recompiles); parity reference."""
@@ -481,19 +493,6 @@ class ICITransport(_TransportBase):
             self.pool = _run_plan_static(self.pool, tuple(plan), self.axis)
         self.stats["dispatches"] += 1
         self.stats["wqes"] += len(plan)
-
-    # -- host access ("QDMA"): the paper's host<->dev_mem DMA path ---------
-    def host_read(self, peer: int, addr: int, length: int):
-        return jax.device_get(self.pool[peer, addr:addr + length])
-
-    def host_write(self, peer: int, addr: int, data) -> None:
-        """Descriptor-ized QDMA H2C over the sharded pool (see
-        ``LocalTransport.host_write``)."""
-        staged, desc, chunk = pack_staging(
-            data, addr, peer, self.pool.shape[1], self.pool.dtype)
-        with jax.set_mesh(self.mesh):
-            self.pool = _exec_staging(self.pool, staged, desc, chunk)
-        self._account_qdma(chunk)
 
     def host_write_static(self, peer: int, addr: int, data) -> None:
         """Seed QDMA path (recompiles per data length); parity reference."""

@@ -125,8 +125,7 @@ class QueuePair:
     several SQ windows contend for one flush. ``lc`` tags QPs owned by a
     Lookaside Compute kernel — the engine accounts their service
     separately (``stats["lc_service"]``) so host-vs-compute contention on
-    the shared engine is observable. ``arm_times`` stamps each
-    doorbell-covered WQE so the engine can histogram service latency.
+    the shared engine is observable.
     """
     qp_num: int
     local_peer: int
@@ -135,7 +134,6 @@ class QueuePair:
     weight: int = 1
     lc: bool = False
     state: QPState = QPState.RTS
-    arm_times: Deque[float] = field(default_factory=deque)
     sq: Deque[WQE] = field(default_factory=deque)
     rq: Deque[WQE] = field(default_factory=deque)   # pre-posted RECVs
     cq: Deque[CQE] = field(default_factory=deque)

@@ -51,8 +51,7 @@ from repro.kernels.packet_parser import HDR_BYTES
 
 
 def record_latency_us(hist: dict, seconds: float) -> None:
-    """Bucket one latency sample into a pow2-µs ceiling histogram (the
-    same bucketing as ``engine.stats["qp_latency_us"]``)."""
+    """Bucket one latency sample into a pow2-µs ceiling histogram."""
     us = seconds * 1e6
     bucket = 1
     while bucket < us:

@@ -55,6 +55,10 @@ All reductions run in f32 pool words and compute the SUM — callers
 divide for a mean. With integer-valued payloads the result is exact
 regardless of reduction order, which is what the conformance suite's
 byte-parity oracle pins.
+
+While the profiler collects, each all-reduce call, its loads,
+rounds, host partial reduces and read-out are spans (``rdma.coll.*``, see
+``repro.core.rdma.trace``).
 """
 from __future__ import annotations
 
@@ -69,6 +73,7 @@ from repro.core.rdma.doorbell import (collective_wire_words,
                                       plan_ring_all_gather,
                                       plan_ring_allreduce,
                                       plan_ring_reduce_scatter)
+from repro.core.rdma.trace import recording, span
 from repro.core.rdma.verbs import CQEStatus, Opcode, WQE
 
 #: wr_id tokens for collective traffic: engine-wide unique so a round
@@ -89,7 +94,7 @@ def _ledger(engine) -> dict:
     """The engine's ``stats["collectives"]`` ledger, default-initialized."""
     led = engine.stats.setdefault("collectives", {})
     for key in ("all_reduces", "reduce_scatters", "all_gathers", "buckets",
-                "rounds", "chunk_reads", "wire_words", "wire_bytes",
+                "rounds", "wire_bytes",
                 "reduce_words", "flushes", "overlapped_flushes"):
         led.setdefault(key, 0)
     return led
@@ -153,6 +158,7 @@ class RDMACollective:
             engine.host_mem[0].dtype).itemsize if engine.host_mem else 4
         self._bump = {p: pool_base for p in range(self.n)}
         self._slots: List[_Slot] = []
+        self._serial = itertools.count()    # all-reduce calls, for spans
         self.stats = _ledger(engine)
 
     # ------------------------------------------------------------ plumbing
@@ -199,12 +205,13 @@ class RDMACollective:
     # ------------------------------------------------------- round driver
     def _load(self, slot: _Slot, shards: Sequence[np.ndarray],
               padded: int) -> None:
-        for p in range(self.n):
-            vec = np.asarray(shards[p], np.float32).reshape(-1)
-            if vec.size < padded:
-                vec = np.concatenate(
-                    [vec, np.zeros(padded - vec.size, np.float32)])
-            self.engine.write_buffer(p, slot.data[p].base, vec)
+        with span("rdma.coll.load"):
+            for p in range(self.n):
+                vec = np.asarray(shards[p], np.float32).reshape(-1)
+                if vec.size < padded:
+                    vec = np.concatenate(
+                        [vec, np.zeros(padded - vec.size, np.float32)])
+                self.engine.write_buffer(p, slot.data[p].base, vec)
 
     def _arm_round(self, st: _BucketState) -> None:
         """Post this round's READs on their QPs and ring ``defer=True``
@@ -230,16 +237,13 @@ class RDMACollective:
                 rkey=slot.data[src].rkey))
             self.engine.ring_sq_doorbell(qp, defer=True)
             st.pending.setdefault(qp.qp_num, []).append(tok)
-            self.stats["chunk_reads"] += 1
-            self.stats["wire_words"] += length
             self.stats["wire_bytes"] += length * self._word_bytes
         st.r += 1
         self.stats["rounds"] += 1
 
-    def _complete_round(self, st: _BucketState) -> None:
-        """Collect this round's CQEs (driving ``flush_doorbells`` between
-        polls so retransmission timers advance on a lossy fabric), then
-        host-reduce the landed scratch words into the data regions."""
+    def _await_round(self, st: _BucketState) -> None:
+        """Collect this round's CQEs, driving ``flush_doorbells`` between
+        polls so retransmission timers advance on a lossy fabric."""
         wanted = {tok for toks in st.pending.values() for tok in toks}
         qps = [self.engine.qps[qn] for qn in st.pending]
         got: Dict[int, object] = {}
@@ -258,17 +262,32 @@ class RDMACollective:
             raise CollectiveError(
                 f"round {st.r - 1}: {len(bad)} failed / "
                 f"{len(wanted) - len(got)} missing chunk READs", bad)
-        for p, addr, words in st.reduces:
-            cur = self.engine.read_buffer(p, addr, words)
-            inc = self.engine.read_buffer(
-                p, st.slot.scratch[p].base, words)
-            self.engine.write_buffer(p, addr, np.asarray(cur)
-                                     + np.asarray(inc))
-            self.stats["reduce_words"] += words
+        if st.reduces and recording():
+            # While spans are recorded, wait for the round's executor
+            # here, inside the round's span, so that the reduce's span
+            # holds the reduce alone. Otherwise the reduce's first read
+            # waits, its slice already queued behind the executor: a
+            # wait of its own costs a dispatch round trip per reduce.
+            self.engine.transport.pool.block_until_ready()
+
+    def _reduce_round(self, st: _BucketState) -> None:
+        """Host-reduce the round's landed scratch words into the data
+        regions: read both operands, add, stage the sum back."""
+        if not st.reduces:
+            return
+        with span("rdma.coll.reduce"):
+            for p, addr, words in st.reduces:
+                cur = self.engine.read_buffer(p, addr, words)
+                inc = self.engine.read_buffer(
+                    p, st.slot.scratch[p].base, words)
+                self.engine.write_buffer(p, addr, np.asarray(cur)
+                                         + np.asarray(inc))
+                self.stats["reduce_words"] += words
 
     def _read_out(self, st: _BucketState) -> List[np.ndarray]:
-        return [np.asarray(self.engine.read_buffer(
-            p, st.slot.data[p].base, st.words)) for p in range(self.n)]
+        with span("rdma.coll.readout"):
+            return [np.asarray(self.engine.read_buffer(
+                p, st.slot.data[p].base, st.words)) for p in range(self.n)]
 
     # ------------------------------------------------------------- public
     def all_reduce_buckets(self, bucket_shards: Sequence[Sequence],
@@ -283,41 +302,54 @@ class RDMACollective:
         ``flush_doorbells`` executes them all — a flush serving more
         than one bucket is ledgered as overlapped (bucket i's wire phase
         riding with bucket i+1's, the comm/compute overlap metric).
+        A tick's wire part (arm, flush, CQE poll and, while spans are
+        recorded, the wait for the executor before a host reduce) is the
+        span ``rdma.coll.round``; each bucket's host partial reduce
+        follows it.
         """
         algorithm = algorithm or self.algorithm
         plan = self._plan(algorithm)
-        results: List[Optional[List[np.ndarray]]] = [None] * len(
-            bucket_shards)
+        n_buckets = len(bucket_shards)
+        results: List[Optional[List[np.ndarray]]] = [None] * n_buckets
         inflight: List[tuple] = []      # (bucket_idx, _BucketState)
         pending = list(enumerate(bucket_shards))
-        self.stats["all_reduces"] += len(bucket_shards)
-        self.stats["buckets"] += len(bucket_shards)
-        while pending or inflight:
-            while pending and len(inflight) < self.pipeline_depth:
-                idx, shards = pending.pop(0)
-                st = self._new_state(shards, plan)
-                if not st.rounds:       # n == 1: nothing on the wire
-                    results[idx] = self._read_out(st)
-                    st.slot.busy = False
+        self.stats["all_reduces"] += n_buckets
+        self.stats["buckets"] += n_buckets
+        words = sum(int(np.size(b[0])) for b in bucket_shards)
+        with span("rdma.coll.allreduce", allreduce=next(self._serial),
+                  buckets=n_buckets,
+                  bucket_bytes=words * self._word_bytes / max(1, n_buckets)):
+            tick = 0
+            while pending or inflight:
+                while pending and len(inflight) < self.pipeline_depth:
+                    idx, shards = pending.pop(0)
+                    st = self._new_state(shards, plan)
+                    if not st.rounds:   # n == 1: nothing on the wire
+                        results[idx] = self._read_out(st)
+                        st.slot.busy = False
+                        continue
+                    inflight.append((idx, st))
+                if not inflight:
                     continue
-                inflight.append((idx, st))
-            if not inflight:
-                continue
-            for _, st in inflight:
-                self._arm_round(st)
-            self.stats["flushes"] += 1
-            if len(inflight) > 1:
-                self.stats["overlapped_flushes"] += 1
-            self.engine.flush_doorbells()
-            still = []
-            for idx, st in inflight:
-                self._complete_round(st)
-                if st.r == len(st.rounds):
-                    results[idx] = self._read_out(st)
-                    st.slot.busy = False
-                else:
-                    still.append((idx, st))
-            inflight = still
+                with span("rdma.coll.round", round=tick):
+                    for _, st in inflight:
+                        self._arm_round(st)
+                    self.stats["flushes"] += 1
+                    if len(inflight) > 1:
+                        self.stats["overlapped_flushes"] += 1
+                    self.engine.flush_doorbells()
+                    for _, st in inflight:
+                        self._await_round(st)
+                tick += 1
+                still = []
+                for idx, st in inflight:
+                    self._reduce_round(st)
+                    if st.r == len(st.rounds):
+                        results[idx] = self._read_out(st)
+                        st.slot.busy = False
+                    else:
+                        still.append((idx, st))
+                inflight = still
         return results              # type: ignore[return-value]
 
     def all_reduce(self, shards: Sequence,
@@ -373,7 +405,8 @@ class RDMACollective:
             self._arm_round(st)
             self.stats["flushes"] += 1
             self.engine.flush_doorbells()
-            self._complete_round(st)
+            self._await_round(st)
+            self._reduce_round(st)
 
 
 def ideal_wire_words(algorithm: str, n_peers: int, words: int) -> int:

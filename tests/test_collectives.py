@@ -50,7 +50,7 @@ def _int_shards(rng, n: int, words: int):
 @pytest.mark.parametrize("algorithm", ["ring", "rd"])
 def test_allreduce_parity(n, algorithm):
     """Byte parity vs psum across pow2 and non-pow2 peer counts, and the
-    wire-word ledger must match the α–β ideal exactly."""
+    wire-byte ledger must match the α–β ideal exactly."""
     rng = np.random.default_rng(n)
     words = 100                        # non-multiple of n: padding path
     eng = _engine(n)
@@ -60,7 +60,7 @@ def test_allreduce_parity(n, algorithm):
     want = _psum_oracle(shards)
     for p in range(n):
         assert np.array_equal(got[p][:words], want[p]), (algorithm, n, p)
-    assert coll.stats["wire_words"] == ideal_wire_words(
+    assert coll.stats["wire_bytes"] == 4 * ideal_wire_words(
         algorithm, n, words)
 
 

@@ -184,10 +184,6 @@ class TestSharedEngineContention:
         assert eng.stats["qp_service"][lc_qp.qp_num] == 3   # 2 READ + 1 WRITE
         assert eng.stats["lc_service"] == {lc_qp.qp_num: 3}
         assert eng.stats["lc_wqes"] == 3
-        # latency histogram ledger covers every serviced WQE
-        for q in (hqp.qp_num, lc_qp.qp_num):
-            assert (sum(eng.stats["qp_latency_us"][q].values())
-                    == eng.stats["qp_service"][q])
         while hqp.pending():
             eng.flush_doorbells()
         assert [c.wr_id for c in eng.poll_cq(hqp, 64)] == list(range(6))

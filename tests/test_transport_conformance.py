@@ -240,8 +240,6 @@ class TestDRRConformance:
         total = sum(service[qp.qp_num] for qp in qps)
         for qp, w in zip(qps, weights):
             assert abs(service[qp.qp_num] / total - w / 6) <= 0.05, service
-            assert (sum(eng.stats["qp_latency_us"][qp.qp_num].values())
-                    == service[qp.qp_num])
 
     def test_drr_exact_share_when_weight_exceeds_flush_budget(self):
         """Regression: the engine snapshots at most flush_budget WQEs per
