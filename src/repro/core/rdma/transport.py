@@ -16,8 +16,12 @@ depends only on two **buckets**:
   * slots  — WQE count padded up to a power of two (min 8); padded rows
              carry ``length = 0`` and are masked no-ops,
   * chunk  — max transfer length padded up to a power of two (min 16);
-             every move gathers ``chunk`` lanes and scatters only the
-             first ``length`` of them (``mode='drop'`` discards the rest).
+             on one device every move reads a ``chunk``-word window at
+             the source and at the destination, shifts the source words
+             into place, keeps the destination's own words outside the
+             first ``length``, and writes the window back in place; the
+             collective program gathers ``chunk`` lanes and scatters the
+             first ``length`` (``mode='drop'`` discards the rest).
 
 Steady-state traffic with fresh addresses therefore hits a warm XLA
 compile cache: the addresses are *operands*, not static arguments. The
@@ -169,20 +173,36 @@ def pack_staging(data, addr: int, peer: int, pool_size: int, dtype
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def _exec_descriptors_local(pool: jax.Array, desc: jax.Array,
                             chunk: int) -> jax.Array:
-    """Single-device executor: sequential masked gather -> scatter per
-    descriptor. Gather indices are clipped (over-reads land in-bounds and
-    are never scattered); scatter lanes past ``length`` point one past the
-    row end and are dropped."""
+    """Single-device executor: each descriptor moves through a window of
+    ``w = min(chunk, pool_size)`` words, so a step touches O(chunk) words
+    of the pool whatever the pool's size.
+
+    The source and destination windows start at ``min(addr, pool_size -
+    w)`` (never off the row end); the source window is rotated so word
+    ``src_addr + j`` lands at ``dst_addr + j``; destination words outside
+    ``[dst_addr, dst_addr + length)`` keep their own values, and the
+    window is written back in place. Both windows are read before the
+    write, so a move that overlaps itself in one row reads every source
+    word first. The rotation runs on the words' bits (XLA may lower it
+    to arithmetic on the words), so payloads move bit for bit. WQEs lie
+    inside their rows (the engine checks each against its MR)."""
     pool_size = pool.shape[1]
-    lane = jnp.arange(chunk, dtype=jnp.int32)
+    w = min(chunk, pool_size)
+    lane = jnp.arange(w, dtype=jnp.int32)
+    bits = jnp.dtype(f"uint{8 * pool.dtype.itemsize}")
 
     def step(i, pool):
-        d = desc[i]
-        src, dst = d[0], d[1]
-        src_addr, dst_addr, length = d[2], d[3], d[4]
-        vals = pool[src, jnp.clip(src_addr + lane, 0, pool_size - 1)]
-        sidx = jnp.where(lane < length, dst_addr + lane, pool_size)
-        return pool.at[dst, sidx].set(vals, mode="drop")
+        src, dst, src_addr, dst_addr, length = (desc[i, c] for c in range(5))
+        s0 = jnp.minimum(src_addr, pool_size - w)
+        d0 = jnp.minimum(dst_addr, pool_size - w)
+        vals = jax.lax.dynamic_slice(pool, (src, s0), (1, w))[0]
+        old = jax.lax.dynamic_slice(pool, (dst, d0), (1, w))[0]
+        off = dst_addr - d0
+        vals = jax.lax.bitcast_convert_type(
+            jnp.roll(jax.lax.bitcast_convert_type(vals, bits),
+                     off - (src_addr - s0)), pool.dtype)
+        win = jnp.where((lane >= off) & (lane < off + length), vals, old)
+        return jax.lax.dynamic_update_slice(pool, win[None], (dst, d0))
 
     return jax.lax.fori_loop(0, desc.shape[0], step, pool)
 

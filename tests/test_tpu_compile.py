@@ -125,6 +125,18 @@ def test_pool_executors_fit_hbm(one_chip, program):
     assert total < HBM_BYTES, total
 
 
+@pytest.mark.parametrize("chunk", [16, 1 << 18])
+def test_descriptor_executor_temp_is_window_sized(one_chip, chunk):
+    """The benchmark's two-peer pool of 2^28 words a peer: each WQE moves
+    through a chunk-sized window, so the temp stays far below the 2 GiB
+    pool (a lane scatter over the flattened pool needs 3 pools of it)."""
+    lowered = _exec_descriptors_local.lower(
+        _spec((2, 1 << 28), jnp.float32, one_chip),
+        _spec((64, 5), jnp.int32, one_chip), chunk=chunk)
+    temp = lowered.compile().memory_analysis().temp_size_in_bytes
+    assert temp < 64 * 2 ** 20, temp
+
+
 @pytest.mark.parametrize("step", ["prefill", "decode"])
 def test_tinyllama_serve_step_compiles(one_chip, step):
     cfg = get_config("tinyllama-1.1b")

@@ -78,6 +78,66 @@ class TestCompileCache:
                 err_msg=f"divergence on trial {trial}")
 
 
+WINDOW_CASES = ("row_end", "pool_below_chunk", "same_row_overlap",
+                "cross_row", "zero_length", "bit_patterns")
+
+
+def _window_case_rows(case, rng, pool):
+    """One descriptor table's ``(src, dst, src_addr, dst_addr, length)``
+    rows, drawn to put the windowed executor at one of its edges."""
+    rows = []
+    for i in range(int(rng.integers(1, 12))):
+        ln = int(rng.integers(1, pool // 2))
+        if case == "pool_below_chunk" and i == 0:
+            ln = int(rng.integers(513, pool + 1))  # chunk 1024 > pool
+        sa, da = (int(a) for a in rng.integers(0, pool - ln + 1, size=2))
+        src, dst = (int(p) for p in rng.integers(0, 2, size=2))
+        if case == "row_end":
+            sa, da = (int(rng.choice([a, pool - ln])) for a in (sa, da))
+        elif case == "same_row_overlap":
+            dst = src
+            da = int(np.clip(sa + rng.integers(-ln + 1, ln), 0, pool - ln))
+        elif case == "cross_row":
+            dst = 1 - src
+        elif case == "zero_length" and rng.random() < 0.5:
+            ln = 0
+        rows.append((src, dst, sa, da, ln))
+    return rows
+
+
+@pytest.mark.parametrize("case", WINDOW_CASES)
+def test_window_executor_matches_numpy_replay(case):
+    """The single-device executor moves each WQE through a chunk-sized
+    window of the pool; on seeded random tables it must equal a numpy
+    replay in table order, word for word. ``pool_below_chunk`` takes
+    non-pow2 pools whose chunk bucket (pow2) exceeds the row, and
+    ``bit_patterns`` fills the pool with arbitrary 32-bit words, NaN
+    payloads included."""
+    import jax.numpy as jnp
+    from repro.core.rdma.transport import (_exec_descriptors_local,
+                                           pack_descriptors)
+    rng = np.random.default_rng(WINDOW_CASES.index(case))
+    for trial in range(8):
+        pool = (int(rng.integers(600, 1024)) if case == "pool_below_chunk"
+                else int(rng.choice([1000, 1024])))
+        rows = _window_case_rows(case, rng, pool)
+        if case == "bit_patterns":
+            init = rng.integers(0, 2 ** 32, size=(2, pool),
+                                dtype=np.uint32).view(np.float32)
+        else:
+            init = rng.standard_normal((2, pool)).astype(np.float32)
+        desc, chunk = pack_descriptors([("xfer", *r) for r in rows], pool)
+        got = np.asarray(_exec_descriptors_local(jnp.asarray(init), desc,
+                                                 chunk))
+        want = init.copy()
+        for src, dst, sa, da, ln in rows:
+            want[dst, da:da + ln] = want[src, sa:sa + ln].copy()
+        np.testing.assert_array_equal(
+            got.view(np.uint32), want.view(np.uint32),
+            err_msg=f"{case} trial {trial}: pool {pool} chunk {chunk} "
+                    f"rows {rows}")
+
+
 class TestCoalescer:
     def test_merges_contiguous_run(self):
         plan = [("xfer", 0, 1, i, 100 + i, 1) for i in range(50)]
