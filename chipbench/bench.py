@@ -1,14 +1,30 @@
-"""The harness: finds a cell's configuration, traffic and per-layer readers by
-name in ``BENCHMARK.json``, runs the cell's loop once, and assembles the
-result line.
+"""The harness: finds a cell's configuration, traffic, loop and per-layer
+readers by name in ``BENCHMARK.json``, runs the cell's loop once, and
+assembles the result line.
 
-A cell is added with files alone: a configuration under ``configs/``, a
-traffic mix under ``traffic/`` (its ``loop`` key names the loop that reads
-it) and, for a new per-layer metric, a reader under ``metrics/`` that defines
-``read(run) -> float | None`` (``bench.reader`` says how it is named).
+A cell is added with new files and new entries alone; no file the benchmark
+already has changes:
+
+- ``configs/<config>.json``: the configuration as it is run, and, where
+  the references of ``refs.py`` do not serve it, a module of its own plain
+  reference under ``chipbench/``;
+- ``traffic/<traffic>.json``: the traffic mix; its ``loop`` key names the
+  loop kind that reads it;
+- ``loops/<loop>.py``, for a new loop kind: ``LOOP`` (a ``cells.Loop``),
+  ``FAULTS`` (the faults its cells can have) and ``CONTROL`` (its control),
+  with the factory of any fault or control not in ``faults.SHARED``
+  (``loop_module``, ``faults.plant``);
+- ``tiny/configs/<config>.json`` and ``tiny/traffic/<traffic>.json``: the
+  keys the CPU tests change to run the cell at a tiny size;
+- ``metrics/<quantity>.py``, for a new per-layer metric: its reader,
+  ``read(run) -> float | None`` (``reader`` says how it is named);
+- in ``BENCHMARK.json``: the configuration and the cell, new metrics, and
+  the cell's name appended to the ``workloads`` list of each metric it
+  reports.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -59,6 +75,18 @@ def resolve(workload: str, root: str = ROOT) -> SimpleNamespace:
         run_seconds=b["run_seconds"])
 
 
+@functools.cache
+def _load(path: str, prefix: str):
+    """The module in the file ``path``, loaded once in a process: a fault
+    that patches a loop's class patches the class that runs."""
+    stem = os.path.basename(path)[:-len(".py")]
+    name = prefix + stem.replace(".", "_").replace("-", "_")
+    sp = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(sp)
+    sp.loader.exec_module(mod)
+    return mod
+
+
 def reader(metric: dict, root: str = ROOT):
     """The ``read`` function of ``metrics/<quantity>.py``, where the quantity
     is the metric's name less a trailing ``.<moves>``: one reader serves a
@@ -66,12 +94,15 @@ def reader(metric: dict, root: str = ROOT):
     name, suffix = metric["name"], "." + metric["moves"]
     if name.endswith(suffix):
         name = name[:-len(suffix)]
-    path = os.path.join(root, "chipbench", "metrics", name + ".py")
-    mod_name = "chipbench_metric_" + name.replace(".", "_").replace("-", "_")
-    sp = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(sp)
-    sp.loader.exec_module(mod)
-    return mod.read
+    return _load(os.path.join(root, "chipbench", "metrics", name + ".py"),
+                 "chipbench_metric_").read
+
+
+def loop_module(kind: str, root: str = ROOT):
+    """``loops/<kind>.py``, the loop of the traffic mixes whose ``loop`` is
+    ``kind``: its ``LOOP`` class, ``FAULTS`` and ``CONTROL``."""
+    return _load(os.path.join(root, "chipbench", "loops", kind + ".py"),
+                 "chipbench_loop_")
 
 
 def peaks(kind: str) -> dict:
@@ -141,7 +172,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     timed. Raises ``NoChip`` without the cell's TPU chips (unless
     ``require_chip`` is false, as in the CPU tests)."""
     import jax
-    from chipbench.cells import LOOPS, Spans
+    from chipbench.cells import Spans
     from repro.core.rdma import transport as tp
 
     r = resolve(workload, root)
@@ -157,8 +188,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         f"workload {workload}, seed {seed}, {seconds} s, trace {int(trace)}")
     counter = CompileCounter()
     spans = Spans()
-    loop = LOOPS[r.traffic["loop"]](r.config, r.traffic, seed, seconds,
-                                    spans)
+    loop = loop_module(r.traffic["loop"], root).LOOP(
+        r.config, r.traffic, seed, seconds, spans)
     loop.setup()
     setup_s = time.perf_counter() - start
     spans.events.clear()

@@ -1,4 +1,3 @@
-import copy
 import json
 import os
 import shutil
@@ -17,45 +16,50 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 
 import pytest  # noqa: E402
 
-#: the cells at a size the CPU runs in seconds; widths of the published
-#: configurations are cut here only, never in the benchmark's own files
-TINY = {
-    "rdma_perftest_1chip": {"pool_words_per_peer": 1 << 14},
-    "ddp_grad_sync_4chip": {"pool_words_per_peer": 1 << 12,
-                            "bucket_words": 1000,
-                            "transport": "LocalTransport"},
-}
-TINY_TRAFFIC = {
-    "read_64B_b50": {"tape_batches": 8, "warmup_batches": 2},
-    "read_1MiB_b50": {"message_words": 128, "batch": 8, "tape_batches": 8,
-                      "warmup_batches": 1},
-    "allreduce_25MiB_ring": {},
-}
+from chipbench.bench import load_json as load  # noqa: E402
+
+#: where each configuration's and traffic mix's tiny size is kept: the keys
+#: that the CPU tests change, never the benchmark's own files
+TINY = os.path.join("chipbench", "tiny")
 
 
-def make_tiny_root(path: str, ici: bool = False) -> str:
-    """A copy of the benchmark at a tiny size under ``path``: the same
-    BENCHMARK.json, loops and readers, with small pools and buckets."""
-    os.makedirs(os.path.join(path, "chipbench", "configs"), exist_ok=True)
-    os.makedirs(os.path.join(path, "chipbench", "traffic"), exist_ok=True)
-    shutil.copytree(os.path.join(HERE, "metrics"),
-                    os.path.join(path, "chipbench", "metrics"),
-                    dirs_exist_ok=True)
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), path)
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    for c in bench["configs"]:
-        cfg = json.load(open(os.path.join(ROOT, c["file"])))
-        cfg.update(copy.deepcopy(TINY[c["name"]]))
+def tiny_files(cell: dict) -> tuple[str, str]:
+    """The tiny files of a cell of ``BENCHMARK.json``, relative to the root:
+    its configuration's and its traffic's."""
+    return (os.path.join(TINY, "configs", cell["config"] + ".json"),
+            os.path.join(TINY, "traffic", cell["traffic"] + ".json"))
+
+
+def make_tiny_root(path: str, ici: bool = False, src: str = ROOT) -> str:
+    """A copy of the benchmark at ``src`` at a tiny size under ``path``: the
+    same BENCHMARK.json, loops and readers, each configuration and traffic
+    mix updated from its tiny file."""
+    for d in ("configs", "traffic"):
+        os.makedirs(os.path.join(path, "chipbench", d), exist_ok=True)
+    for d in ("metrics", "loops"):
+        shutil.copytree(os.path.join(src, "chipbench", d),
+                        os.path.join(path, "chipbench", d),
+                        dirs_exist_ok=True)
+    shutil.copy(os.path.join(src, "BENCHMARK.json"), path)
+    bench = load(os.path.join(src, "BENCHMARK.json"))
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    for w in bench["workloads"]:
+        tiny_config, tiny_traffic = tiny_files(w)
+        cfg = load(os.path.join(src, files[w["config"]]))
+        cfg.update(load(os.path.join(src, tiny_config)))
         if ici and cfg["n_peers"] == 4:
             cfg["transport"] = "ICITransport"
-        json.dump(cfg, open(os.path.join(path, c["file"]), "w"))
-    for w in bench["workloads"]:
-        name = w["traffic"]
-        tr = json.load(open(os.path.join(HERE, "traffic", name + ".json")))
-        tr.update(copy.deepcopy(TINY_TRAFFIC[name]))
-        json.dump(tr, open(os.path.join(path, "chipbench", "traffic",
-                                        name + ".json"), "w"))
+        dump(cfg, os.path.join(path, files[w["config"]]))
+        traffic = os.path.join("chipbench", "traffic", w["traffic"] + ".json")
+        tr = load(os.path.join(src, traffic))
+        tr.update(load(os.path.join(src, tiny_traffic)))
+        dump(tr, os.path.join(path, traffic))
     return path
+
+
+def dump(obj: dict, path: str) -> None:
+    with open(path, "w") as f:
+        json.dump(obj, f)
 
 
 @pytest.fixture(scope="session")

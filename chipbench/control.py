@@ -25,13 +25,15 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
 
-def plants(workload: str, asked: list[str], root: str) -> list[str]:
-    from chipbench import bench, faults
-    faults_of, control = faults.APPLIES[
-        bench.resolve(workload, root).traffic["loop"]]
+def plants(kind: str, asked: list[str], root: str) -> list[str]:
+    """The plants asked for, with ``control`` and ``faults`` named as the
+    file of the loop kind lists them."""
+    from chipbench import bench
+    loop = bench.loop_module(kind, root)
     out = []
     for a in asked:
-        out += {"control": [control], "faults": list(faults_of)}.get(a, [a])
+        out += {"control": [loop.CONTROL],
+                "faults": list(loop.FAULTS)}.get(a, [a])
     return out
 
 
@@ -48,10 +50,11 @@ def main() -> int:
     if not args.cpu:
         bench.enable_compile_cache()
     readings: dict = {}
-    for plant in plants(args.workload, args.plant, args.root):
+    kind = bench.resolve(args.workload, args.root).traffic["loop"]
+    for plant in plants(kind, args.plant, args.root):
         got = readings.setdefault(plant, {"correct": []})
         for seed in args.seeds:
-            with (faults.plant(plant) if plant != "sound"
+            with (faults.plant(plant, kind, args.root) if plant != "sound"
                   else contextlib.nullcontext()):
                 try:
                     res = bench.run_cell(

@@ -1,9 +1,12 @@
 """Faults planted under the timed path, and the controls, for showing that
 the comparison which decides ``correct`` fails when it should.
 
-Each is a context manager that patches the program for its duration. The
-benchmark's own runs never plant one: ``control.py`` does, on the chip at a
-cell's own size, and ``test_chipbench_faults.py`` at a tiny size.
+Each is a context manager that patches the program for its duration. A loop
+kind's file lists the faults its cells can have (``FAULTS``) and names its
+control (``CONTROL``); the faults here are shared by several loops, a
+control or a fault of one loop is defined in its file. The benchmark's own
+runs never plant one: ``control.py`` does, on the chip at a cell's own size,
+and ``test_chipbench_faults.py`` at a tiny size.
 """
 from __future__ import annotations
 
@@ -11,6 +14,8 @@ import contextlib
 import functools
 
 import numpy as np
+
+from chipbench import bench
 
 
 @contextlib.contextmanager
@@ -23,7 +28,7 @@ def patched(obj, name: str, value):
         setattr(obj, name, old)
 
 
-def _both_transports(attr: str, wrap):
+def both_transports(attr: str, wrap):
     from repro.core.rdma import transport as tp
     stack = contextlib.ExitStack()
     for cls in (tp.LocalTransport, tp.ICITransport):
@@ -34,8 +39,8 @@ def _both_transports(attr: str, wrap):
 def unchanged():
     """A step that returns its state unchanged: the executor leaves the
     pool as it was."""
-    return _both_transports("_run_descriptors",
-                            lambda orig: lambda self, desc, chunk: None)
+    return both_transports("_run_descriptors",
+                           lambda orig: lambda self, desc, chunk: None)
 
 
 def half_batch():
@@ -45,7 +50,7 @@ def half_batch():
         def run(self, plan):
             return orig(self, list(plan)[:(len(plan) + 1) // 2])
         return run
-    return _both_transports("execute_batch", wrap)
+    return both_transports("execute_batch", wrap)
 
 
 def altered():
@@ -60,19 +65,7 @@ def altered():
             if length:
                 self.pool = self.pool.at[dst, dst_addr].add(1.0)
         return run
-    return _both_transports("_run_descriptors", wrap)
-
-
-def truncated():
-    """Control of the verbs cells, which state no precision: it
-    breaks the guarantee that a transfer delivers all ``length`` words, by
-    leaving the last word of every transfer undelivered."""
-    def wrap(orig):
-        @functools.wraps(orig)
-        def run(self, plan):
-            return orig(self, [e[:5] + (max(0, e[5] - 1),) for e in plan])
-        return run
-    return _both_transports("execute_batch", wrap)
+    return both_transports("_run_descriptors", wrap)
 
 
 def no_exchange():
@@ -110,30 +103,16 @@ def no_exchange():
     return patched(tp, "_make_ici_program", make)
 
 
-def bf16_sum():
-    """Control of the all-reduce cell: the plain reference, computed one
-    precision below the configuration's float32 (bfloat16), in the place of
-    ``RDMACollective.all_reduce``."""
-    from chipbench import refs
-    from repro.train.collectives import RDMACollective
-
-    def all_reduce(self, shards, algorithm=None):
-        out = refs.sum_bf16(np.stack([np.asarray(s) for s in shards]))
-        return [out.copy() for _ in range(self.n)]
-    return patched(RDMACollective, "all_reduce", all_reduce)
-
-
-FAULTS = {"unchanged": unchanged, "half_batch": half_batch,
+#: the faults any loop may list; a loop's file may define others, and its
+#: control, under the names it lists
+SHARED = {"unchanged": unchanged, "half_batch": half_batch,
           "altered": altered, "no_exchange": no_exchange}
-CONTROLS = {"truncated": truncated, "bf16_sum": bf16_sum}
-
-#: the faults each loop kind's cells can have, and each kind's control
-APPLIES = {
-    "verbs_closed_loop": (("unchanged", "half_batch", "altered"), "truncated"),
-    "allreduce_closed_loop": (("unchanged", "half_batch", "altered",
-                               "no_exchange"), "bf16_sum"),
-}
 
 
-def plant(name: str):
-    return {**FAULTS, **CONTROLS}[name]()
+def plant(name: str, loop: str, root: str = bench.ROOT):
+    """Plant fault or control ``name`` of the loop kind ``loop``: the factory
+    of that name in ``loops/<loop>.py``, else the shared one above."""
+    mod = bench.loop_module(loop, root)
+    if name not in (*mod.FAULTS, mod.CONTROL):
+        raise KeyError(f"loop {loop!r} lists no fault or control {name!r}")
+    return (getattr(mod, name, None) or SHARED[name])()

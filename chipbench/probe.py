@@ -31,11 +31,14 @@ def slots(pools: list[int], chunks: list[int], counts: list[int]) -> None:
         for chunk in chunks:
             for n in counts:
                 desc = jnp.zeros((n, 5), jnp.int32)
-                _exec_descriptors_local(pool, desc, chunk).block_until_ready()
+                # each dispatch takes the pool the last one made, as the
+                # transport does, so an executor may donate the pool
+                pool = _exec_descriptors_local(pool, desc, chunk)
+                pool.block_until_ready()
                 t = time.perf_counter()
                 for _ in range(3):
-                    out = _exec_descriptors_local(pool, desc, chunk)
-                out.block_until_ready()
+                    pool = _exec_descriptors_local(pool, desc, chunk)
+                pool.block_until_ready()
                 dt = (time.perf_counter() - t) / 3
                 print(f"pool 2^{lg} chunk {chunk} slots {n}: {dt * 1e3:.3f} "
                       f"ms per dispatch, {dt / n * 1e6:.1f} us per slot",

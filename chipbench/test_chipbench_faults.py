@@ -12,10 +12,12 @@ import pytest
 from chipbench import bench, faults
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-LOOP_OF = {"verbs_read_64B_b50": "verbs_closed_loop",
-             "verbs_read_1MiB_b50": "verbs_closed_loop",
-             "allreduce_25MiB_ring": "allreduce_closed_loop"}
-ONE_DEVICE = list(LOOP_OF)
+SPEC = bench.spec()
+#: every cell, each on one CPU device at its tiny size
+CELLS = [w["name"] for w in SPEC["workloads"]]
+#: faults of the exchange between chips, which no cell on one device has:
+#: ``test_four_peer_ici_faults_turn_correct_false`` plants them
+ICI_ONLY = ("no_exchange",)
 
 
 def run(root, workload, seed=2 ** 31 + 7, seconds=0.3, trace=False):
@@ -23,15 +25,22 @@ def run(root, workload, seed=2 ** 31 + 7, seconds=0.3, trace=False):
                           require_chip=False, root=root, log=lambda *a: None)
 
 
+def loop_of(workload, root=bench.ROOT):
+    """The loop kind of a cell, as its traffic names it, and the kind's
+    file."""
+    kind = bench.resolve(workload, root).traffic["loop"]
+    return kind, bench.loop_module(kind, root)
+
+
 def cases():
-    for w in ONE_DEVICE:
-        fs, control = faults.APPLIES[LOOP_OF[w]]
-        for name in fs + (control,):
-            if name != "no_exchange":
+    for w in CELLS:
+        mod = loop_of(w)[1]
+        for name in (*mod.FAULTS, mod.CONTROL):
+            if name not in ICI_ONLY:
                 yield w, name
 
 
-@pytest.mark.parametrize("workload", ONE_DEVICE)
+@pytest.mark.parametrize("workload", CELLS)
 def test_sound_run_is_correct_and_reports_its_metrics(tiny_root, workload):
     res = run(tiny_root, workload)
     assert res["correct"], res["checks"]
@@ -44,7 +53,7 @@ def test_sound_run_is_correct_and_reports_its_metrics(tiny_root, workload):
 
 @pytest.mark.parametrize("workload,plant", list(cases()))
 def test_fault_or_control_turns_correct_false(tiny_root, workload, plant):
-    with faults.plant(plant):
+    with faults.plant(plant, loop_of(workload)[0], tiny_root):
         res = run(tiny_root, workload)
     assert not res["correct"], (plant, res["checks"])
 
@@ -60,10 +69,10 @@ def test_allreduce_checks_a_seeded_sample_copied_into_buffers_of_setup(
         tiny_root):
     """The window copies the sampled results into buffers made in set-up
     and keeps no array of the program's."""
-    from chipbench.cells import LOOPS, Spans
+    from chipbench.cells import Spans
     r = bench.resolve("allreduce_25MiB_ring", tiny_root)
-    loop = LOOPS[r.traffic["loop"]](r.config, r.traffic, 2 ** 31 + 9, 0.3,
-                                    Spans())
+    loop = loop_of("allreduce_25MiB_ring", tiny_root)[1].LOOP(
+        r.config, r.traffic, 2 ** 31 + 9, 0.3, Spans())
     loop.setup()
     kept = loop.kept
     assert kept.shape == (r.traffic["check_sample"], 4, r.config["bucket_words"])
@@ -78,23 +87,26 @@ def test_allreduce_checks_a_seeded_sample_copied_into_buffers_of_setup(
 
 
 def test_four_peer_ici_faults_turn_correct_false(tmp_path):
-    """The all-reduce over ICITransport on four virtual CPU devices, sound,
-    under each fault (the exchange between chips left out among them) and
-    under its control."""
+    """Each four-chip cell over ICITransport on four virtual CPU devices,
+    sound, under each fault its loop lists (the exchange between chips left
+    out among them) and under its control."""
     from chipbench.conftest import make_tiny_root
     root = make_tiny_root(str(tmp_path), ici=True)
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    out = subprocess.run(
-        [sys.executable, os.path.join(HERE, "control.py"), "--cpu",
-         "--root", root, "--workload", "allreduce_25MiB_ring",
-         "--seconds", "0.3", "--seeds", "3",
-         "--plant", "sound", "faults", "control"],
-        env=env, capture_output=True, text=True, timeout=300)
-    assert out.returncode == 0, out.stderr[-2000:]
-    got = json.loads(out.stdout.strip().splitlines()[-1])
-    assert got.pop("sound")["correct"] == [True]
-    assert set(got) == {"unchanged", "half_batch", "altered", "no_exchange",
-                        "bf16_sum"}
-    for plant, readings in got.items():
-        assert readings["correct"] == [False], plant
+    four = [w["name"] for w in SPEC["workloads"] if w["chips"] == 4]
+    assert four
+    for workload in four:
+        mod = loop_of(workload, root)[1]
+        out = subprocess.run(
+            [sys.executable, os.path.join(HERE, "control.py"), "--cpu",
+             "--root", root, "--workload", workload,
+             "--seconds", "0.3", "--seeds", "3",
+             "--plant", "sound", "faults", "control"],
+            env=env, capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        got = json.loads(out.stdout.strip().splitlines()[-1])
+        assert got.pop("sound")["correct"] == [True]
+        assert set(got) == {*mod.FAULTS, mod.CONTROL}
+        for plant, readings in got.items():
+            assert readings["correct"] == [False], (workload, plant)

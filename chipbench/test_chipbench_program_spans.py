@@ -9,7 +9,6 @@ from types import SimpleNamespace
 import pytest
 
 from chipbench import bench
-from chipbench.conftest import TINY
 
 VERBS = ("engine.flush_self_us_per_wqe.msg_rate",
          "transport.dispatch_us_per_wqe.msg_rate")
@@ -57,7 +56,7 @@ def test_traced_verbs_run_reports_flush_and_dispatch_time(tiny_root):
 def test_traced_allreduce_reports_reduce_time_and_host_copies(tiny_root):
     res = run(tiny_root, "allreduce_25MiB_ring", True)
     assert res["correct"]
-    cfg = TINY["ddp_grad_sync_4chip"]
+    cfg = bench.resolve("allreduce_25MiB_ring", tiny_root).config
     assert res["metrics"]["client.host_reduce_ms.algbw"]["value"] > 0
     assert res["metrics"]["client.host_copy_bytes_per_byte.algbw"][
         "value"] == pytest.approx(host_copy_per_byte(
